@@ -47,6 +47,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.paper_models import PAPER_MODELS
 from repro.core.engine import CompiledModel
 from repro.core.quantize import quantize_graph
@@ -110,7 +111,10 @@ def main(fast: bool = False, cache_dir=None, manifest_out=None,
         for name in MODELS:
             qg = _quantized(name)
             cache = AotCache(os.path.join(root, name))
-            cold_us, cold_cm = _boot_us(qg, cache, max_batch)
+            # JAX's persistent cache would serve the cold boot's compiles
+            # from an earlier run; the cold phase must really compile.
+            with compile_cache.disabled():
+                cold_us, cold_cm = _boot_us(qg, cache, max_batch)
             assert cold_cm.compile_events > 0, \
                 f"{name}: cold boot compiled nothing — stale cache dir?"
             warm_us, warm_cm = _boot_us(qg, cache, max_batch)

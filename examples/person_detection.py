@@ -7,6 +7,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.configs.paper_models import build_person
 from repro.core import CompiledModel, Interpreter
 from repro.core.memory import memory_report
@@ -51,4 +52,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

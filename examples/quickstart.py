@@ -6,6 +6,7 @@ AOT compiled engine (MicroFlow architecture) — then compare memory plans.
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import CompiledModel, Interpreter
 from repro.core import graph as G
 from repro.core.builder import GraphBuilder
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models import model as M
@@ -71,4 +72,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

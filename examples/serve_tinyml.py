@@ -32,6 +32,7 @@ import asyncio
 
 import numpy as np
 
+from repro import compile_cache
 from repro.serve.executor import ThreadPoolExecutorBackend
 from repro.serve.faults import FaultInjector
 from repro.serve.registry import ClassPolicy, build_paper_registry
@@ -134,7 +135,7 @@ async def main(n_requests: int = 256, chaos: bool = False):
     reg2 = build_paper_registry(("sine",), max_batch=4)
     async with reg2:
         y_served = await reg2.infer("sine", reg2.quantize_input("sine", x))
-    y_direct = reg2._entries["sine"].model.predict_q(
+    y_direct = reg2.model("sine").predict_q(
         reg2.quantize_input("sine", x))
     assert np.array_equal(np.asarray(y_served), np.asarray(y_direct))
     print("served rows are bit-identical to direct predict_q ✓")
@@ -147,4 +148,5 @@ if __name__ == "__main__":
                     help="inject seeded dispatch faults behind the "
                          "resilient executor (see module docstring)")
     args = ap.parse_args()
+    compile_cache.enable()
     asyncio.run(main(args.n_requests, chaos=args.chaos))

@@ -9,6 +9,7 @@ protocol (1000 test samples, U(-0.1, 0.1) additive noise).
 import numpy as np
 
 from benchmarks.bench_accuracy import sine_metrics, train_sine_weights
+from repro import compile_cache
 from repro.configs.paper_models import build_sine
 from repro.core import CompiledModel
 from repro.core.quantize import quantize_graph
@@ -37,4 +38,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -144,14 +144,16 @@ def plan_fingerprint(plan: ExecutionPlan) -> str:
 
 def environment_info() -> Dict[str, str]:
     """The executable-compatibility envelope: serialized XLA executables
-    are only loadable under the same jax/jaxlib version and backend
-    platform, so the manifest records where it was produced and
-    :func:`verify_manifest` rejects a cache from anywhere else (C004)."""
+    are only loadable under the same jax/jaxlib version, backend platform
+    and device generation (a v5e executable is not a v4 one), so the
+    manifest records where it was produced and :func:`verify_manifest`
+    rejects a cache from anywhere else (C004)."""
     import jax
     import jaxlib
     return {"jax": jax.__version__,
             "jaxlib": getattr(jaxlib, "__version__", "unknown"),
-            "backend": jax.default_backend()}
+            "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind}
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ through this one shared walker.
 from __future__ import annotations
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 def prim_counts(fn, *specs) -> dict:
@@ -22,9 +23,9 @@ def prim_counts(fn, *specs) -> dict:
             for v in eq.params.values():
                 vs = v if isinstance(v, (tuple, list)) else [v]
                 for u in vs:
-                    if isinstance(u, jax.core.ClosedJaxpr):
+                    if isinstance(u, ClosedJaxpr):
                         walk(u.jaxpr)
-                    elif isinstance(u, jax.core.Jaxpr):
+                    elif isinstance(u, Jaxpr):
                         walk(u)
 
     walk(jax.make_jaxpr(fn)(*specs).jaxpr)

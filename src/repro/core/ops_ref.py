@@ -62,6 +62,24 @@ def _saturate_i8(y):
     return jnp.clip(jnp.round(y), I8_MIN, I8_MAX).astype(jnp.int8)
 
 
+def requant_consts(s_x, s_w, s_y, z_y, b_q=None, s_b=None, z_b=None):
+    """Per-channel ``(bias_term, rescale)`` of Eqs. (4)/(7)/(10), float32.
+
+    Computed in float64 and rounded to float32 once. The compile-time fold
+    (``preprocess.fold_weighted_op``) and the unfolded ops below both take
+    them from here, so the interpreter and the compiled engine requantize
+    with the same constants and agree bit for bit. The same formula taken
+    in float32 steps can land an ulp away, which flips the rounding of an
+    accumulator that sits near a half step."""
+    if b_q is None:
+        bias_term = np.asarray(z_y, np.float64)
+    else:
+        bias_term = z_y + (s_b / s_y) * (np.asarray(b_q).astype(np.float64)
+                                         - z_b)
+    rescale = (np.asarray(s_x, np.float64) * s_w) / s_y
+    return np.asarray(bias_term, np.float32), np.asarray(rescale, np.float32)
+
+
 def _fused_bounds(fused: str, z_y, s_y):
     """Quantized clamp bounds for fused activations (Eqs. (15), (17))."""
     lo = -jnp.inf
@@ -123,11 +141,8 @@ def fully_connected_q(
     z_x = jnp.asarray(z_x, jnp.int32)
     z_w = jnp.asarray(z_w, jnp.int32)
     inner = acc - z_w * sum_x - z_x * sum_w + n * z_x * z_w
-    if b_q is None:
-        bias_term = jnp.asarray(z_y, jnp.float32)
-    else:
-        bias_term = z_y + (s_b / s_y) * (b_q.astype(jnp.float32) - z_b)
-    rescale = (s_x * s_w) / s_y
+    bias_term, rescale = requant_consts(s_x, s_w, s_y, z_y, b_q, s_b,
+                                        z_b)
     y = bias_term + rescale * inner.astype(jnp.float32)
     lo, hi = _fused_bounds(fused, jnp.asarray(z_y), jnp.asarray(s_y, jnp.float32))
     return _saturate_i8(jnp.clip(y, lo, hi))
@@ -211,11 +226,8 @@ def conv2d_q(
     z_x = jnp.asarray(z_x, jnp.int32)
     z_f = jnp.asarray(z_f, jnp.int32)
     inner = acc - z_f * sum_x - z_x * sum_f + count * z_x * z_f
-    if b_q is None:
-        bias_term = jnp.asarray(z_y, jnp.float32)
-    else:
-        bias_term = z_y + (s_b / s_y) * (b_q.astype(jnp.float32) - z_b)
-    rescale = (s_x * s_f) / s_y
+    bias_term, rescale = requant_consts(s_x, s_f, s_y, z_y, b_q, s_b,
+                                        z_b)
     y = bias_term + rescale * inner.astype(jnp.float32)
     lo, hi = _fused_bounds(fused, jnp.asarray(z_y), jnp.asarray(s_y, jnp.float32))
     return _saturate_i8(jnp.clip(y, lo, hi))
@@ -278,11 +290,8 @@ def depthwise_conv2d_q(
     z_x = jnp.asarray(z_x, jnp.int32)
     z_w = jnp.asarray(z_w, jnp.int32)
     inner = acc - z_w * sum_x - z_x * sum_w + count * z_x * z_w
-    if b_q is None:
-        bias_term = jnp.asarray(z_y, jnp.float32)
-    else:
-        bias_term = z_y + (s_b / s_y) * (b_q.astype(jnp.float32) - z_b)
-    rescale = (s_x * s_w) / s_y
+    bias_term, rescale = requant_consts(s_x, s_w, s_y, z_y, b_q, s_b,
+                                        z_b)
     y = bias_term + rescale * inner.astype(jnp.float32)
     lo, hi = _fused_bounds(fused, jnp.asarray(z_y), jnp.asarray(s_y, jnp.float32))
     return _saturate_i8(jnp.clip(y, lo, hi))

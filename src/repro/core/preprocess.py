@@ -29,7 +29,8 @@ import numpy as np
 
 from . import graph as G
 from . import registry
-from .ops_ref import FoldedConsts, MXU_LANES, clamp_bounds, round_up
+from .ops_ref import (FoldedConsts, MXU_LANES, clamp_bounds,
+                      requant_consts, round_up)
 
 
 def _scalar_or_channel(qp: G.QParams):
@@ -59,17 +60,16 @@ def fold_weighted_op(g: G.Graph, op: G.OpNode) -> FoldedConsts:
 
     if b_t is not None:
         s_b, z_b = _scalar_or_channel(b_t.qparams)
-        bias_term = z_y + (s_b / s_y) * (b_t.data.astype(np.float64) - z_b)
+        bias_term, rescale = requant_consts(s_x, s_w, s_y, z_y, b_t.data,
+                                            s_b, z_b)
     else:
-        bias_term = np.asarray(z_y, np.float64)
-
-    rescale = (np.asarray(s_x, np.float64) * s_w) / s_y
+        bias_term, rescale = requant_consts(s_x, s_w, s_y, z_y)
     w_sum_zx = (np.asarray(z_x, np.int64) * sum_w).astype(np.int32)
     const_off = (count * np.asarray(z_x, np.int64) * z_w).astype(np.int32)
 
     return FoldedConsts(
-        bias_term=np.asarray(bias_term, np.float32),
-        rescale=np.asarray(rescale, np.float32),
+        bias_term=bias_term,
+        rescale=rescale,
         w_sum_zx=w_sum_zx,
         const_off=const_off,
         z_w=np.asarray(z_w, np.int32),

@@ -4,8 +4,14 @@ MobileNet-style depthwise convolutions dominate the paper's person-detector
 model. TPU adaptation: channels are the fast (lane) dimension, so the kernel
 blocks over channels (bc lanes per grid step) and keeps the whole spatial
 extent in VMEM (TinyML feature maps are tiny: 96×96×8 int8 = 72 KiB). The
-kh×kw taps are a static unrolled loop of strided VMEM slices — the MCU's
+kh×kw taps are a static unrolled loop of strided VMEM loads — the MCU's
 sliding-window "view extraction" (Algorithm 1) becomes vectorized lane math.
+
+The int8 block is widened once into an int32 VMEM scratch and every tap is
+a (possibly strided) load from that scratch: Mosaic lowers strided loads
+only for 32-bit data, and refuses a strided slice of an in-register value.
+The scratch costs 4·H·W·bc bytes — 1.2 MiB at the person model's largest
+block (50×50×128).
 
 Input must be pre-padded (ops.qdwconv_folded handles SAME), kernel is VALID.
 """
@@ -16,27 +22,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 I8_MIN, I8_MAX = -128, 127
 
 
 def _qdwconv_kernel(x_ref, w_ref, bias_ref, resc_ref, wsum_ref, coff_ref,
-                    zw_ref, out_ref, *, kh, kw, stride, lo, hi, c_true):
+                    zw_ref, out_ref, x32_ref, *, kh, kw, stride, lo, hi,
+                    c_true):
     sh, sw = stride
     cc = pl.program_id(1)
-    _, H, W, bc = x_ref.shape
-    _, oh, ow, _ = out_ref.shape
-    x = x_ref[...].astype(jnp.int32)          # (1, H, W, bc)
-    w = w_ref[...].astype(jnp.int32)          # (kh, kw, bc)
+    _, oh, ow, bc = out_ref.shape
+    x32_ref[...] = x_ref[...].astype(jnp.int32)   # (1, H, W, bc)
+    w = w_ref[...].astype(jnp.int32)              # (kh, kw, bc)
 
     acc = jnp.zeros((1, oh, ow, bc), jnp.int32)
     sum_x = jnp.zeros((1, oh, ow, bc), jnp.int32)
     for i in range(kh):                       # static tap loop (Algorithm 1)
         for j in range(kw):
-            sl = jax.lax.slice(
-                x, (0, i, j, 0),
-                (1, i + (oh - 1) * sh + 1, j + (ow - 1) * sw + 1, bc),
-                (1, sh, sw, 1))               # (1, oh, ow, bc)
+            sl = x32_ref[:, pl.ds(i, oh, stride=sh),
+                         pl.ds(j, ow, stride=sw), :]   # (1, oh, ow, bc)
             acc = acc + sl * w[i, j]          # ΣΣ X W   per channel
             sum_x = sum_x + sl                # ΣΣ X     per channel
 
@@ -76,7 +81,7 @@ def qdwconv(x_q, w_q, bias_term, rescale, w_sum_zx, const_off, z_w,
     const_spec = pl.BlockSpec((1, 1, 1, bc), lambda n, cc: (0, 0, 0, cc))
 
     return pl.pallas_call(
-        functools.partial(_qdwconv_kernel, kh=kh, kw=kw, stride=stride,
+        functools.partial(_qdwconv_kernel, kh=kh, kw=kw, stride=tuple(stride),
                           lo=lo, hi=hi, c_true=c_true),
         grid=(b, c // bc),
         in_specs=[
@@ -86,5 +91,6 @@ def qdwconv(x_q, w_q, bias_term, rescale, w_sum_zx, const_off, z_w,
         ],
         out_specs=pl.BlockSpec((1, oh, ow, bc), lambda n, cc: (n, 0, 0, cc)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, c), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((1, H, W, bc), jnp.int32)],
         interpret=interpret,
     )(x_q, w_q, *consts)

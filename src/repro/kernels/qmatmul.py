@@ -35,11 +35,14 @@ def _qmatmul_kernel(x_ref, w_ref, bias_ref, resc_ref, wsum_ref, coff_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         sumx_ref[...] = jnp.zeros_like(sumx_ref)
 
-    x = x_ref[...].astype(jnp.int32)          # (bm, bk)
-    w = w_ref[...].astype(jnp.int32)          # (bk, bn)
+    x = x_ref[...]                            # (bm, bk) int8
+    # int8 x int8 -> int32 is the MXU's integer contraction; widening the
+    # operands first would ask for an int32 matmul, which the MXU lacks.
     acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    sumx_ref[...] += jnp.sum(x, axis=1, keepdims=True)   # (bm, 1)
+        x, w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
+    sumx_ref[...] += jnp.sum(x.astype(jnp.int32), axis=1,
+                             keepdims=True)   # (bm, 1)
 
     @pl.when(k == n_k - 1)
     def _finish():

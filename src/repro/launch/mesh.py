@@ -16,19 +16,10 @@ HBM_BW = 819e9                # 819 GB/s
 ICI_BW = 50e9                 # ~50 GB/s per link
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where it exists (jax.sharding.AxisType landed in
-    JAX 0.6); earlier JAX meshes are implicitly Auto, so omitting it is
-    equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types on any supported JAX version."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    """jax.make_mesh with Auto axis types."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -114,6 +114,10 @@ class ServingRegistry:
     def models(self) -> tuple:
         return tuple(self._entries)
 
+    def model(self, name: str) -> CompiledModel:
+        """The compiled model served under ``name``."""
+        return self._entry(name).model
+
     def __contains__(self, name: str) -> bool:
         return name in self._entries
 

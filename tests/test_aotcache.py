@@ -170,11 +170,15 @@ def test_manifest_rejects_environment_mismatch(graphs, tmp_path):
     cache = AotCache(str(tmp_path))
     cm = _model(graphs).warmup_batched(2, cache=cache)
     fp = plan_fingerprint(cm.exec_plan)
-    man = cache.manifest(fp)
-    man["environment"]["jaxlib"] = "0.0.0"
-    info, findings = verify_manifest(man, cm.exec_plan, 2)
-    assert not info["ok"]
-    assert any(f.code == "C004" for f in findings)
+    # another jaxlib, and another chip generation under the same backend
+    for key, other in (("jaxlib", "0.0.0"), ("device_kind", "TPU v4")):
+        man = cache.manifest(fp)
+        assert key in man["environment"]
+        man["environment"][key] = other
+        info, findings = verify_manifest(man, cm.exec_plan, 2)
+        assert not info["ok"]
+        assert [f.where for f in findings if f.code == "C004"] \
+            == [f"environment.{key}"]
 
 
 def test_manifest_audit_cross_check(graphs, tmp_path):

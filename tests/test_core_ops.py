@@ -140,6 +140,38 @@ def test_depthwise_folded_equals_unfolded(seed, same, stride):
     np.testing.assert_array_equal(y1, y2)
 
 
+def test_fc_folded_equals_unfolded_where_float32_steps_would_not():
+    """The rescale taken in float32 steps, ``(s_x*s_w)/s_y``, is one ulp away
+    from the fold's (float64, rounded once) for these scales, and at this
+    accumulator (-19369) the two round to different int8 values (-48 and
+    -49). Both paths take their constants from ``requant_consts``, so the
+    interpreter's unfolded op and the compiled fold still agree."""
+    from repro.core.graph import FULLY_CONNECTED, Graph, OpNode, TensorSpec
+    from repro.core.preprocess import fold_weighted_op
+    s_x, s_w, s_y = (np.float32(0.033772003), np.float32(0.004041201),
+                     np.float32(0.05132952))
+    z0, z_y = np.int32(0), np.int32(3)
+    assert np.float32(np.float32(s_x * s_w) / s_y) != \
+        K.requant_consts(s_x, s_w, s_y, z_y)[1]
+    x_q = np.array([[127, 127, 1]], np.int8)
+    w_q = np.array([[-127], [-25], [-65]], np.int8)  # Σ X W = -19369
+
+    y1 = np.asarray(K.fully_connected_q(
+        x_q, w_q, None, s_x=s_x, z_x=z0, s_w=s_w, z_w=z0, s_b=np.float32(1),
+        z_b=z0, s_y=s_y, z_y=z_y))
+    g = Graph(
+        tensors=[TensorSpec("x", x_q.shape, "int8", QParams(s_x, z0)),
+                 TensorSpec("w", w_q.shape, "int8", QParams(s_w, z0),
+                            data=w_q),
+                 TensorSpec("y", (1, 1), "int8", QParams(s_y, z_y))],
+        ops=[OpNode(FULLY_CONNECTED, [0, 1], [2], {"fused": "NONE"})],
+        inputs=[0], outputs=[2])
+    y2 = np.asarray(K.fully_connected_folded(x_q, w_q,
+                                             fold_weighted_op(g, g.ops[0])))
+    np.testing.assert_array_equal(y1, y2)
+    assert y2.item() == -49
+
+
 def test_relu_eq14_piecewise():
     s_x, z_x = np.float32(0.1), np.int32(10)
     s_y, z_y = np.float32(0.1), np.int32(-20)

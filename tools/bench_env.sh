@@ -3,19 +3,14 @@
 #
 #   tools/bench_env.sh python -m benchmarks.run --no-interpret
 #
-# Sets the allocator + XLA flags the serving benches are sensitive to,
-# then execs the wrapped command. Everything degrades gracefully — each
-# knob is applied only when the underlying artifact exists, and an
-# already-set variable is never overridden, so the wrapper is safe in CI,
-# in containers without tcmalloc, and on CPU-only boxes:
+# Sets the allocator knobs the serving benches are sensitive to, then
+# execs the wrapped command. Each knob is applied only when the underlying
+# artifact exists, and an already-set variable is never overridden:
 #
 # * tcmalloc LD_PRELOAD — the dispatch hot path churns small Python/numpy
 #   allocations; tcmalloc's thread-cached freelists cut the malloc share
 #   of per-request overhead. The large-alloc report threshold is raised
 #   so arena/bucket allocations don't spam stderr into the CSV capture.
-# * XLA latency-hiding scheduler + highest-priority async stream — lets
-#   compiled executables overlap host dispatch with device work, which is
-#   what the off-loop executor benches measure. No-ops on CPU.
 # * TF_CPP_MIN_LOG_LEVEL=4 — keeps XLA/TSL banner noise out of timing
 #   runs' stderr.
 set -euo pipefail
@@ -26,8 +21,5 @@ if [[ -z "${LD_PRELOAD:-}" && -e "$TCMALLOC" ]]; then
 fi
 export TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD="${TCMALLOC_LARGE_ALLOC_REPORT_THRESHOLD:-60000000000}"
 export TF_CPP_MIN_LOG_LEVEL="${TF_CPP_MIN_LOG_LEVEL:-4}"
-if [[ -z "${XLA_FLAGS:-}" ]]; then
-    export XLA_FLAGS="--xla_gpu_enable_latency_hiding_scheduler=true --xla_gpu_enable_highest_priority_async_stream=true"
-fi
 
 exec "$@"
