@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
@@ -151,11 +152,19 @@ class ExecutionPlan:
                 ctx = R.OpContext(g, op, i, folded=folded.get(i),
                                   use_pallas=use_pallas, n_pages=paged.get(i),
                                   layout=lay)
-                env[op.outputs[0]] = run(ctx, [val(t, keep_padded=lay is not None)
-                                               for t in op.inputs])
+                # the graph layer rides every device op's op_name metadata
+                with jax.named_scope(f"{i:02d}_{op.op.lower()}"):
+                    env[op.outputs[0]] = run(
+                        ctx, [val(t, keep_padded=lay is not None)
+                              for t in op.inputs])
 
             return tuple(val(t) for t in g.outputs)
 
+        # a stable executable name (``jit_serve_<graph>``) on the device's
+        # XLA Modules line, in place of the anonymous ``fn``
+        fn.__name__ = fn.__qualname__ = (
+            f"{'serve' if batched else 'predict'}_"
+            + re.sub(r"\W", "_", g.name))
         return fn
 
 
@@ -355,9 +364,11 @@ class CompiledModel:
     def compile(self):
         if self._aot is None:
             with self._compile_lock:
-                if self._aot is None:  # double-checked: compile-once under
-                    lowered = self._fn.lower(*self._input_specs())  # racing
-                    self._aot = lowered.compile()                   # callers
+                # double-checked: compile-once under racing callers
+                if self._aot is None:
+                    with engine_span("compile", kind="percall"):
+                        self._aot = self._fn.lower(
+                            *self._input_specs()).compile()
                     self._note_compile("percall")
         return self._aot
 
@@ -399,8 +410,9 @@ class CompiledModel:
                       if jax.default_backend() != "cpu" else ())
             fn = jax.jit(self.exec_plan.lower(batched=True),
                          donate_argnums=donate)
-            exe = fn.lower(
-                *self.exec_plan.batched_input_specs(bucket)).compile()
+            with engine_span("compile", kind="bucket", bucket=bucket):
+                exe = fn.lower(
+                    *self.exec_plan.batched_input_specs(bucket)).compile()
             with self._compile_lock:
                 self._batched_aot[bucket] = exe
                 self._note_compile("bucket", bucket=bucket)
@@ -590,8 +602,9 @@ class CompiledModel:
                 if fn is None:
                     spec = jax.ShapeDtypeStruct(tuple(shape),
                                                 np.dtype(dtype))
-                    fn = jax.jit(lambda a: jnp.pad(a, widths)).lower(
-                        spec).compile()
+                    with engine_span("compile", kind="stage_pad"):
+                        fn = jax.jit(lambda a: jnp.pad(a, widths)).lower(
+                            spec).compile()
                     self._stage_pad[key] = fn
                     self._note_compile("stage_pad", shape=tuple(shape))
         return fn
@@ -635,8 +648,9 @@ class CompiledModel:
         exactly what the staged ``jnp.pad`` produces) holds for the next
         checkout. The pool keeps at most ``_staging_cap`` sets per bucket;
         extras are dropped to the GC."""
-        for b in bufs:
-            b[:rows] = 0
+        with engine_span("stage_rezero"):
+            for b in bufs:
+                b[:rows] = 0
         with self._staging_lock:
             pool = self._staging.setdefault(bucket, [])
             if len(pool) < self._staging_cap:
@@ -650,10 +664,20 @@ class CompiledModel:
         physical buffer equals the fused bucket-fill + lane pad output."""
         bucket = bufs[0].shape[0]
         exe = self.compile_batched(bucket)
-        args = [jnp.asarray(b) for b in bufs]  # H2D, already padded
+        with engine_span("stage_h2d"):
+            args = [jnp.asarray(b) for b in bufs]  # H2D, already padded
+        return self._run_bucket(exe, args, bucket, rows)
+
+    @staticmethod
+    def _run_bucket(exe, args, bucket: int, rows: int):
+        """The bucket executable on device arguments, to host rows. The
+        device span covers the launch AND the host sync (np.asarray) — what
+        a request actually waits for."""
         with engine_span("device", bucket=bucket, rows=rows):
-            outs = exe(*args)
-            outs = tuple(np.asarray(o)[:rows] for o in outs)
+            with engine_span("launch"):
+                outs = exe(*args)
+            with engine_span("fetch"):
+                outs = tuple(np.asarray(o)[:rows] for o in outs)
         return outs if len(outs) > 1 else outs[0]
 
     def staged_infer(self, rows: list):
@@ -672,8 +696,10 @@ class CompiledModel:
         try:
             dst = bufs[0]
             window = tuple(slice(0, d) for d in t.shape)  # logical region
-            for i, row in enumerate(rows):
-                dst[(i,) + window] = np.asarray(row, t.dtype).reshape(t.shape)
+            with engine_span("stage_rows"):
+                for i, row in enumerate(rows):
+                    dst[(i,) + window] = np.asarray(
+                        row, t.dtype).reshape(t.shape)
             return self.predict_q_staged(bufs, n)
         finally:
             self.release_staging(bucket, bufs, n)
@@ -686,19 +712,15 @@ class CompiledModel:
             a = np.asarray(arr, t.dtype).reshape((-1,) + t.shape)
             assert a.shape[0] == batch, (
                 f"all inputs must share the batch dim: {a.shape[0]} != {batch}")
-            a = jnp.asarray(a)  # H2D of the real rows only
+            with engine_span("stage_h2d"):
+                a = jnp.asarray(a)  # H2D of the real rows only
             widths = self._entry_widths(tid, batch)
             if any(w for _, w in widths):
                 with engine_span("pad_stage", batch=batch):
                     a = self._staged_pad(a.shape, widths, a.dtype)(a)
             args.append(a)
         exe = self.compile_batched(batch)
-        # the device span covers the executable call AND the host sync
-        # (np.asarray) — what a request actually waits for
-        with engine_span("device", bucket=bucket_for(batch), rows=batch):
-            outs = exe(*args)
-            outs = tuple(np.asarray(o)[:batch] for o in outs)
-        return outs if len(outs) > 1 else outs[0]
+        return self._run_bucket(exe, args, bucket_for(batch), batch)
 
     def predict_q(self, *inputs):
         """Graph-dtype in / graph-dtype out. Inputs may carry one extra

@@ -88,7 +88,7 @@ def can_lower_noninterpret():
 
         fn = pl.pallas_call(
             kern, out_shape=jax.ShapeDtypeStruct((8, LANE), jnp.float32),
-            interpret=False)
+            interpret=False, name="lower_probe")
         out = jax.jit(fn)(jnp.zeros((8, LANE), jnp.float32))
         jax.block_until_ready(out)
         _NONINTERPRET_PROBE = (True, None)

@@ -62,4 +62,5 @@ def paged_qmatmul(x_q, w_q, bias_term, rescale, w_sum_zx, const_off, z_w,
         out_specs=pl.BlockSpec((m, page), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
         interpret=interpret,
+        name="paged_matmul",
     )(x_q, w_q, *consts)

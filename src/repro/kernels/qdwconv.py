@@ -93,4 +93,5 @@ def qdwconv(x_q, w_q, bias_term, rescale, w_sum_zx, const_off, z_w,
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, c), jnp.int8),
         scratch_shapes=[pltpu.VMEM((1, H, W, bc), jnp.int32)],
         interpret=interpret,
+        name="qdwconv",
     )(x_q, w_q, *consts)

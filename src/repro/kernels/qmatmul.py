@@ -106,6 +106,7 @@ def qmatmul(x_q, w_q, bias_term, rescale, w_sum_zx, const_off, z_w,
             pltpu.VMEM((bm, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="qmatmul",
     )(x_q, w_q, *consts)
 
 
@@ -146,4 +147,5 @@ def fmatmul(x, w, *, bm=128, bn=128, bk=128, interpret=False):
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="fmatmul",
     )(x, w)

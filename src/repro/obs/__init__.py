@@ -7,7 +7,9 @@ Three layers, all bounded-memory and driven by the injected clock:
   -> validate -> retry/degrade -> complete|shed|expire``) with per-stage
   latency histograms; span context rides ``DispatchCtx.trace`` through
   the scheduler, executors, and the resilience ladder, and the engine
-  attaches pad/device/compile spans via a thread-local scope.
+  attaches pad/device/compile spans via a thread-local scope. Each
+  flush phase is also a ``jax.profiler.TraceAnnotation`` (``repro/...``),
+  so a profiler attached to a live server shows it beside the device ops.
 * :mod:`repro.obs.flight` — a fixed-capacity ring buffer of recent
   span/fault/breaker/retry events, dumped to ``results/flightrec.json``
   on FlushError, breaker-open, or an SLO-miss burst.

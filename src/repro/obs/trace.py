@@ -24,6 +24,17 @@ thread, executors re-enter the handle's scope explicitly (via
 :func:`engine_span` / :func:`engine_event` helpers attach pad/device/
 compile spans to the active flush without the engine importing anything
 from the serving layer.
+
+The same spans go on the profiler's timeline: :func:`engine_span` always
+enters a ``jax.profiler.TraceAnnotation`` named ``repro/<phase>``, traced
+or not, which records only while a profiler session runs. Attaching
+``jax.profiler`` to a live server therefore shows each flush's phases —
+
+    flush > flush_assemble, dispatch > (stage_rows, stage_h2d,
+            device > (launch, fetch), stage_rezero), validate, resolve
+
+plus ``compile`` — beside the device ops they drive. These phases are
+children of the stages above and get no histogram of their own.
 """
 from __future__ import annotations
 
@@ -32,10 +43,12 @@ import threading
 from collections import OrderedDict, deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Span", "StageHist", "Tracer", "TraceHandle", "NULL_TRACER",
-    "STAGES", "TERMINALS", "engine_span", "engine_event",
-    "current_handle",
+    "STAGES", "TERMINALS", "TIMELINE_PREFIX", "engine_span",
+    "engine_event", "current_handle",
 ]
 
 # Span taxonomy (the names histograms and tests key on).  "queue" is the
@@ -150,11 +163,15 @@ class _Flush:
 # the worker thread via TraceHandle.bind()/scope().
 # --------------------------------------------------------------------------
 
-_tls = threading.local()
+class _Local(threading.local):
+    handle: Optional["TraceHandle"] = None  # no scope: a miss costs nothing
+
+
+_tls = _Local()
 
 
 def current_handle() -> Optional["TraceHandle"]:
-    return getattr(_tls, "handle", None)
+    return _tls.handle
 
 
 class _Scope:
@@ -165,7 +182,7 @@ class _Scope:
         self.prev: Optional[TraceHandle] = None
 
     def __enter__(self) -> "_Scope":
-        self.prev = getattr(_tls, "handle", None)
+        self.prev = _tls.handle
         _tls.handle = self.handle
         return self
 
@@ -173,40 +190,52 @@ class _Scope:
         _tls.handle = self.prev
 
 
-class _EngineSpan:
-    """Context manager emitted by :func:`engine_span`; near-free when no
-    trace scope is active on this thread."""
+#: Prefix of every program span on the profiler's timeline.
+TIMELINE_PREFIX = "repro/"
 
-    __slots__ = ("name", "attrs", "handle", "t0")
 
-    def __init__(self, name: str, attrs: Dict[str, Any]):
+class _Span:
+    """A profiler ``TraceAnnotation`` that also records itself, on the
+    flush's clock, into the :class:`Tracer` of the flush whose trace scope
+    was active when it was made."""
+
+    __slots__ = ("name", "attrs", "handle", "t0", "ann")
+
+    def __init__(self, name: str, attrs: Dict[str, Any],
+                 handle: "TraceHandle"):
         self.name = name
         self.attrs = attrs
-        self.handle = getattr(_tls, "handle", None)
+        self.handle = handle
+        self.ann = TraceAnnotation(TIMELINE_PREFIX + name)
         self.t0 = 0.0
 
-    def __enter__(self) -> "_EngineSpan":
-        h = self.handle
-        if h is not None:
-            self.t0 = h.clock.now()
+    def __enter__(self) -> "_Span":
+        self.ann.__enter__()
+        self.t0 = self.handle.clock.now()
         return self
 
     def __exit__(self, *exc: Any) -> None:
         h = self.handle
-        if h is not None:
-            h.span(self.name, self.t0, h.clock.now(), **self.attrs)
+        h.span(self.name, self.t0, h.clock.now(), **self.attrs)
+        self.ann.__exit__(*exc)
 
 
-def engine_span(name: str, **attrs: Any) -> _EngineSpan:
-    """Time a stage inside the engine (pad_stage, device) and attach it to
-    the flush whose scope is active on this thread; no-op otherwise."""
-    return _EngineSpan(name, attrs)
+def engine_span(name: str, **attrs: Any):
+    """Time one phase of a flush as a context manager: always a
+    ``jax.profiler.TraceAnnotation`` named ``TIMELINE_PREFIX + name``
+    (about a microsecond, recorded only while a profiler session runs),
+    and also a span of the flush whose trace scope is active on this
+    thread, if any. Spans are per flush, never per request."""
+    h = _tls.handle
+    if h is None:
+        return TraceAnnotation(TIMELINE_PREFIX + name)
+    return _Span(name, attrs, h)
 
 
 def engine_event(name: str, **attrs: Any) -> None:
     """Record a point event (e.g. an AOT compile) against the active
     flush; no-op when no trace scope is active on this thread."""
-    h = getattr(_tls, "handle", None)
+    h = _tls.handle
     if h is not None:
         h.event(name, h.clock.now(), **attrs)
 

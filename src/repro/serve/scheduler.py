@@ -58,12 +58,13 @@ from typing import Callable, Optional
 import numpy as np
 
 from repro.core.engine import bucket_floor, dispatched_bucket_rows
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.trace import NULL_TRACER, Tracer, engine_span
 from .executor import DispatchCtx, InferenceExecutor, InlineExecutor, \
     RowOutcomes
 from .metrics import ModelMetrics
 
 DEFAULT_CLASS = "default"
+_NO_SCOPE = contextlib.nullcontext()
 
 
 class QueueFullError(RuntimeError):
@@ -774,61 +775,65 @@ class MicroBatcher:
             trace=handle)
 
     def _flush(self) -> None:
-        reqs = self._take()
-        if not reqs:
-            return
-        t_take = self.clock.now()
-        if self.tracer.enabled or not self._fast:
-            # legacy lane keeps the pre-teardown shape: unconditional
-            # flush bookkeeping calls (NULL tracer no-ops inside)
-            fid = self.tracer.flush_begin(
-                [r.rid for r in reqs], t_take, model=self.name,
-                rows=len(reqs),
-                bucket=dispatched_bucket_rows(len(reqs), self.max_batch))
-            handle = self.tracer.handle(fid, self.clock)
-        else:  # untraced hot path: skip even the span-argument assembly
-            fid = handle = None
+        # Opened before the flush's trace scope: on the profiler's timeline
+        # it is the parent of every phase below, while a Tracer keeps its
+        # own root span for the flush.
+        with engine_span("flush"):
+            reqs = self._take()
+            if not reqs:
+                return
+            t_take = self.clock.now()
+            if self.tracer.enabled or not self._fast:
+                # legacy lane keeps the pre-teardown shape: unconditional
+                # flush bookkeeping calls (NULL tracer no-ops inside)
+                fid = self.tracer.flush_begin(
+                    [r.rid for r in reqs], t_take, model=self.name,
+                    rows=len(reqs),
+                    bucket=dispatched_bucket_rows(len(reqs), self.max_batch))
+                handle = self.tracer.handle(fid, self.clock)
+            else:  # untraced hot path: skip even the span-argument assembly
+                fid = handle = None
+            # the flush's spans, an inline dispatch's engine spans included,
+            # land on this flush
+            with handle.scope() if handle is not None else _NO_SCOPE:
+                self._dispatch(reqs, fid, handle)
+
+    def _dispatch(self, reqs: list, fid, handle) -> None:
         ex = self.executor
         detached = self._fast and not ex.inline and ex.detached
-        # Prestaged assembly fast path: rows are copied straight into the
-        # engine's pooled physical-layout staging buffers — no np.stack,
-        # no per-flush allocation, no staged device pad. Only flushes that
-        # fit one warmed bucket qualify, and only on the dispatch paths
-        # whose executor calls ``infer`` exactly once (inline / detached);
-        # resilience-wrapped executors keep the stacked-array contract
-        # their retry/bisection semantics are written against.
-        if (self._infer_staged is not None and self._fast
-                and len(reqs) <= self._staged_max
-                and (ex.inline or detached)):
-            infer: Callable = self._infer_staged
-            xs = [r.x for r in reqs]
-        else:
-            infer = self._infer
-            try:
-                # staging included: a malformed request (wrong sample
-                # shape) must poison its batch, not kill the scheduler
-                xs = np.stack([np.asarray(r.x) for r in reqs])
-            except Exception as e:
-                self._fail(reqs, e, fid=fid)
-                return
-        if fid is not None:
-            self.tracer.span(fid, "flush_assemble", t_take,
-                             self.clock.now(), rows=len(reqs))
+        with engine_span("flush_assemble", rows=len(reqs)):
+            # Prestaged assembly fast path: rows are copied straight into
+            # the engine's pooled physical-layout staging buffers — no
+            # np.stack, no per-flush allocation, no staged device pad.
+            # Only flushes that fit one warmed bucket qualify, and only on
+            # the dispatch paths whose executor calls ``infer`` exactly
+            # once (inline / detached); resilience-wrapped executors keep
+            # the stacked-array contract their retry/bisection semantics
+            # are written against.
+            if (self._infer_staged is not None and self._fast
+                    and len(reqs) <= self._staged_max
+                    and (ex.inline or detached)):
+                infer: Callable = self._infer_staged
+                xs = [r.x for r in reqs]
+            else:
+                infer = self._infer
+                try:
+                    # staging included: a malformed request (wrong sample
+                    # shape) must poison its batch, not kill the scheduler
+                    xs = np.stack([np.asarray(r.x) for r in reqs])
+                except Exception as e:
+                    self._fail(reqs, e, fid=fid)
+                    return
         if ex.inline:
             # deterministic fast path: the flush completes synchronously on
             # the event loop (no task hop), exactly the FakeClock contract
             t0 = self.clock.now()
             self.metrics.observe_dispatch(len(reqs))
             try:
-                if handle is not None:
-                    with handle.scope():  # engine spans land on this flush
-                        ys = infer(xs)
-                else:
+                with engine_span("dispatch"):
                     ys = infer(xs)
-                t_disp = self.clock.now()
-                self.tracer.span(fid, "dispatch", t0, t_disp)
-                ys = self._validate_rows(ys, len(reqs))
-                self.tracer.span(fid, "validate", t_disp, self.clock.now())
+                with engine_span("validate"):
+                    ys = self._validate_rows(ys, len(reqs))
             except Exception as e:  # poison batch fails its requests, not
                 self._fail(reqs, e, fid=fid)  # the scheduler — the loop
                 return                        # keeps serving
@@ -977,65 +982,66 @@ class MicroBatcher:
 
     def _distribute(self, reqs: list, ys, t0: float, t1: float,
                     fid=None) -> None:
-        # bucket rows as actually dispatched: predict_q_many chunks on
-        # bucket boundaries, so occupancy reflects real padding, not the
-        # bucket_for(take) a single un-chunked call would have paid
-        by_class: dict = {}
-        for r in reqs:
-            by_class[r.cls] = by_class.get(r.cls, 0) + 1
-        self.metrics.observe_batch(
-            len(reqs), dispatched_bucket_rows(len(reqs), self.max_batch),
-            t1 - t0, by_class=by_class)
-        if self._fast:
-            # batch-granular resolution: one tight set_result loop, then
-            # the flush's terminal accounting folded into ONE metrics call
-            # per class — no per-row observer call on the hot path. The
-            # legacy lane below keeps the per-row shape so the pre-teardown
-            # cost stays reconstructable for the dispatch A/B bench.
-            traced = self.tracer.enabled
-            lats: dict = {}
+        with engine_span("resolve"):
+            # bucket rows as actually dispatched: predict_q_many chunks on
+            # bucket boundaries, so occupancy reflects real padding, not the
+            # bucket_for(take) a single un-chunked call would have paid
+            by_class: dict = {}
+            for r in reqs:
+                by_class[r.cls] = by_class.get(r.cls, 0) + 1
+            self.metrics.observe_batch(
+                len(reqs), dispatched_bucket_rows(len(reqs), self.max_batch),
+                t1 - t0, by_class=by_class)
+            if self._fast:
+                # batch-granular resolution: one tight set_result loop, then
+                # the flush's terminal accounting folded into ONE metrics call
+                # per class — no per-row observer call on the hot path. The
+                # legacy lane below keeps the per-row shape so the pre-teardown
+                # cost stays reconstructable for the dispatch A/B bench.
+                traced = self.tracer.enabled
+                lats: dict = {}
+                for r, y in zip(reqs, ys):
+                    if not r.future.done():
+                        r.future.set_result(y)
+                        lat = t1 - r.t
+                        by = lats.get(r.cls)
+                        if by is None:
+                            by = lats[r.cls] = []
+                        by.append(lat)
+                        if traced:
+                            slo_s = self._policy(r.cls).slo_s
+                            if slo_s is not None and lat > slo_s:
+                                self.tracer.slo_miss(self.name, r.cls, t1,
+                                                     lat, slo_s)
+                            self.tracer.terminal(r.rid, t1, "complete")
+                    else:  # caller cancelled: distinct from infer failure
+                        self.metrics.observe_cancelled(r.cls)
+                        self.tracer.terminal(r.rid, t1, "shed",
+                                             reason="cancelled")
+                for cls, ls in lats.items():
+                    self.metrics.observe_done_many(
+                        ls, cls=cls, slo_s=self._policy(cls).slo_s)
+                self.tracer.flush_end(fid, t1)
+                # recycle inline (taken from the containers by _take:
+                # pool-safe) — no per-row call on the hot path
+                pool, cap = self._pool, self.max_queue
+                for r in reqs:
+                    if len(pool) < cap:
+                        r.x = None
+                        r.future = None
+                        r.rid = None
+                        pool.append(r)
+                return
             for r, y in zip(reqs, ys):
                 if not r.future.done():
-                    r.future.set_result(y)
-                    lat = t1 - r.t
-                    by = lats.get(r.cls)
-                    if by is None:
-                        by = lats[r.cls] = []
-                    by.append(lat)
-                    if traced:
-                        slo_s = self._policy(r.cls).slo_s
-                        if slo_s is not None and lat > slo_s:
-                            self.tracer.slo_miss(self.name, r.cls, t1,
-                                                 lat, slo_s)
-                        self.tracer.terminal(r.rid, t1, "complete")
+                    self._complete(r, y, t1, fid)
                 else:  # caller cancelled: distinct from infer failure
                     self.metrics.observe_cancelled(r.cls)
                     self.tracer.terminal(r.rid, t1, "shed",
                                          reason="cancelled")
-            for cls, ls in lats.items():
-                self.metrics.observe_done_many(
-                    ls, cls=cls, slo_s=self._policy(cls).slo_s)
             self.tracer.flush_end(fid, t1)
-            # recycle inline (taken from the containers by _take:
-            # pool-safe) — no per-row call on the hot path
-            pool, cap = self._pool, self.max_queue
-            for r in reqs:
-                if len(pool) < cap:
-                    r.x = None
-                    r.future = None
-                    r.rid = None
-                    pool.append(r)
-            return
-        for r, y in zip(reqs, ys):
-            if not r.future.done():
-                self._complete(r, y, t1, fid)
-            else:  # caller cancelled: distinct from infer failure
-                self.metrics.observe_cancelled(r.cls)
-                self.tracer.terminal(r.rid, t1, "shed",
-                                     reason="cancelled")
-        self.tracer.flush_end(fid, t1)
-        for r in reqs:  # taken from the containers by _take: pool-safe
-            self._recycle(r)
+            for r in reqs:  # taken from the containers by _take: pool-safe
+                self._recycle(r)
 
     def _distribute_outcomes(self, reqs: list, out: RowOutcomes,
                              t0: float, t1: float, fid=None) -> None:

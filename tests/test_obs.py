@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 from repro.obs.flight import FlightRecorder
-from repro.obs.trace import NULL_TRACER, STAGES, TERMINALS, Tracer
+from repro.obs.trace import (NULL_TRACER, STAGES, TERMINALS,
+                             TIMELINE_PREFIX, Tracer)
 from repro.serve.executor import InlineExecutor
 from repro.serve.faults import FaultInjector
 from repro.serve.metrics import ModelMetrics
@@ -170,6 +171,79 @@ def test_engine_spans_cross_executor_boundary():
         # the mean is 0; the histogram still observed every terminal
         assert tracer.hists["device"].n == 3
     run(body())
+
+
+# ------------------------------------------------------ profiler timeline --
+
+#: The served staged flush's phases below its ``flush`` span.
+FLUSH_PHASES = ("flush_assemble", "dispatch", "stage_rows", "stage_h2d",
+                "device", "launch", "fetch", "stage_rezero", "validate",
+                "resolve")
+
+
+def _profiled_flushes(tmp_path, tracer):
+    """Serve three flushes of 1, 4 and 3 rows of the sine model through
+    the staged path, then compile its per-call executable, under
+    ``jax.profiler``. Returns the program's spans on the timeline as
+    [(name, thread, t0_ns, t1_ns)] and the batcher's metrics."""
+    import jax
+    from jax.profiler import ProfileData
+
+    cm, qxs = _sine_served()
+    b = MicroBatcher.for_model(cm, name="sine", max_batch=4,
+                               max_delay_s=0.05, max_queue=8, tracer=tracer)
+
+    async def body():
+        async with b:
+            for n in (1, 4, 3):
+                await asyncio.gather(*(b.infer(qxs[i]) for i in range(n)))
+
+    with jax.profiler.trace(str(tmp_path)):
+        run(body())
+        cm.compile()  # a cold compile outside any flush
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith(TIMELINE_PREFIX):
+                    spans.append((ev.name[len(TIMELINE_PREFIX):],
+                                  (plane.name, i), ev.start_ns,
+                                  ev.start_ns + ev.duration_ns))
+    return spans, b.metrics
+
+
+def _inside(spans, outer):
+    _, thread, a, b = outer
+    return [s for s in spans
+            if s is not outer and s[1] == thread and a <= s[2] <= s[3] <= b]
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_flush_phases_on_profiler_timeline(tmp_path, traced):
+    """Each served flush puts its phases on the profiler's timeline under
+    the ``repro/`` prefix, nested inside one ``flush`` span, the same set
+    for every flush whatever its row count (no span is per request), with
+    the default NULL_TRACER as with a recording Tracer."""
+    tracer = Tracer() if traced else None
+    spans, met = _profiled_flushes(tmp_path, tracer)
+    names = [s[0] for s in spans]
+    assert set(FLUSH_PHASES) | {"flush", "compile"} <= set(names), names
+    flushes = [s for s in spans if s[0] == "flush"]
+    assert len(flushes) == met.batches == 3 and met.batched_rows == 8
+    for fl in flushes:
+        inner = _inside(spans, fl)
+        assert sorted(s[0] for s in inner) == sorted(FLUSH_PHASES), inner
+        by = {s[0]: s for s in inner}
+        for child in ("launch", "fetch"):
+            assert by[child] in _inside(spans, by["device"])
+        for child in ("stage_rows", "stage_h2d", "device", "stage_rezero"):
+            assert by[child] in _inside(spans, by["dispatch"])
+    # every phase span lies inside a flush: none is left over
+    assert sum(n in FLUSH_PHASES for n in names) == 3 * len(FLUSH_PHASES)
+    if traced:  # the same context manager records into the flush's trace
+        got = {s.name for s in tracer.trees()[-1]["spans"]}
+        assert set(FLUSH_PHASES) - {"resolve"} <= got, got
 
 
 # -------------------------------------------------------- flight recorder --

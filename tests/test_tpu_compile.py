@@ -83,3 +83,67 @@ def test_kernel_compiles_for_v5e(case, one_chip):
     compiled = CASES[case](spec).compile()
     assert "tpu_custom_call" in compiled.as_text()
     print(case, compiled.memory_analysis())
+
+
+def _conv_dw_model():
+    """A quantized conv -> depthwise conv -> FC graph on the Pallas route."""
+    import numpy as np
+
+    from repro.core import CompiledModel
+    from repro.core.builder import GraphBuilder
+    from repro.core.quantize import quantize_graph
+
+    rng = np.random.default_rng(0)
+    b = GraphBuilder("conv-dw")
+    x = b.input("x", (1, 8, 8, 1))
+    h = b.conv2d(x, rng.normal(0, .5, (3, 3, 1, 4)).astype("f"),
+                 rng.normal(size=4).astype("f"), stride=(2, 2),
+                 fused="RELU6")
+    h = b.depthwise_conv2d(h, rng.normal(0, .5, (3, 3, 4, 1)).astype("f"),
+                           rng.normal(size=4).astype("f"), fused="RELU6")
+    h = b.reshape(h, (1, 64))
+    b.output(b.fully_connected(h, rng.normal(0, .5, (64, 2)).astype("f"),
+                               None))
+    qg = quantize_graph(b.build(), [rng.normal(size=(1, 8, 8, 1)).astype("f")
+                                    for _ in range(2)])
+    return CompiledModel(qg, use_pallas=True)
+
+
+#: Scopes of the graph ops that run device work, as ``ExecutionPlan.lower``
+#: names them in every op's ``op_name`` metadata.
+SCOPES = ("00_conv_2d", "01_depthwise_conv_2d", "03_fully_connected")
+
+
+def test_bucket_executable_names_are_stable():
+    """A bucket executable is the module ``jit_serve_<graph>`` and each of
+    its ops carries its graph op's scope in ``op_name`` (here on the CPU,
+    kernels in interpret mode)."""
+    cm = _conv_dw_model()
+    text = cm.compile_batched(2).as_text()
+    assert text.startswith("HloModule jit_serve_conv_dw_int8,"), text[:80]
+    for scope in SCOPES:
+        assert f'op_name="jit(serve_conv_dw_int8)/{scope}/' in text, scope
+
+
+def test_kernels_keep_their_names_on_v5e(one_chip):
+    """Compiled for a described v5e, each Pallas kernel of a bucket
+    executable is an HLO op named by its ``pallas_call`` name — what the
+    benchmark's kernel classes are found by — under its graph op's scope."""
+    import re
+
+    from repro.kernels import ops as kops
+
+    cm = _conv_dw_model()
+    specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+             for s in cm.exec_plan.batched_input_specs(2)]
+    kops.set_interpret(False)
+    try:
+        text = jax.jit(cm.exec_plan.lower(batched=True)).lower(
+            *specs).compile().as_text()
+    finally:
+        kops.set_interpret(None)
+    kernels = re.findall(
+        r'%(\w+)\.\d+ = .*custom_call_target="tpu_custom_call"'
+        r'.*op_name="jit\(serve_conv_dw_int8\)/(\w+)/', text)
+    assert sorted(kernels) == [("qdwconv", SCOPES[1]), ("qmatmul", SCOPES[0]),
+                               ("qmatmul", SCOPES[2])], kernels
