@@ -23,7 +23,8 @@ of which executes the model:
 markdown reports; ``--selftest`` proves the auditor still catches seeded
 bad plans (CI runs both — see ``tools/check.sh``).
 """
-from .budget import PadBudget, audit_pads, measured_pads, pad_budget
+from .budget import (PadBudget, audit_pads, conv_contractions,
+                     measured_pads, pad_budget)
 from .fingerprint import (build_manifest, environment_info,
                           plan_fingerprint, stage_key_id, verify_manifest)
 from .liveness import (ArenaBound, arena_liveness, measure_live_bytes,
@@ -39,6 +40,7 @@ __all__ = [
     "ERROR", "INFO", "WARNING",
     "ArenaBound", "AuditReport", "Finding", "PadBudget", "RouteReport",
     "arena_liveness", "audit_pads", "audit_retrace", "build_manifest",
+    "conv_contractions",
     "environment_info", "errors", "lint_weak_types", "measure_live_bytes",
     "measured_pads", "pad_budget", "paged_peak_bytes", "plan_fingerprint",
     "reachable_buckets", "reachable_chunk_batches", "reachable_stage_keys",

@@ -20,7 +20,7 @@ import numpy as np
 from repro.core import graph as G
 from repro.core.engine import ExecutionPlan, bucket_floor
 
-from .budget import audit_pads
+from .budget import audit_pads, conv_contractions
 from .fingerprint import plan_fingerprint
 from .liveness import arena_liveness, measure_live_bytes, paged_peak_bytes
 from .report import (ERROR, AuditReport, Finding, RouteReport, errors,
@@ -101,6 +101,7 @@ def audit_plan(name: str, plan: ExecutionPlan, max_batch: int = 4,
     # finding C005), so a cache and an audit produced from different plans
     # can never silently co-certify a boot
     rep.fingerprint = plan_fingerprint(plan)
+    rep.convs = conv_contractions(plan)
     return rep
 
 
@@ -231,6 +232,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                   f" B (measured {a.get('measured_peak_bytes', '-')} B)"
                   f"  pads {r.pads.get('budget', '-')}"
                   f"/{r.pads.get('traced', '-')} (budget/traced)")
+        for c in rep.convs:
+            print(f"  conv op {c['op']:<6d} K {c['k_true']} -> K' "
+                  f"{c['k_phys']}, in_lanes {c['in_lanes']}")
         rt = rep.retrace
         print(f"  no-retrace     buckets {rt.get('reachable_buckets')} "
               f"stage-keys {rt.get('reachable_stage_keys')} -> "

@@ -184,6 +184,26 @@ def pad_budget(plan: ExecutionPlan, batched: bool = False,
                      enforceable=enforceable, notes=notes, missed=missed)
 
 
+def conv_contractions(plan: ExecutionPlan) -> List[Dict[str, Any]]:
+    """Per planned Conv2D, the im2col contraction the plan lays out: its
+    true K (kh·kw·Cin real values), the physical K' of the pre-padded
+    filter matrix, and the lane width the conv reads its input at. A k×k
+    conv on a logical input shows ``in_lanes == Cin`` and K' = K rounded
+    up to 128; one on a lane-padded input, K' = kh·kw·in_lanes."""
+    if plan.layout is None:
+        return []
+    g = plan.graph
+    rows = []
+    for i, lay in sorted(plan.layout.layouts.items()):
+        if lay.kind != "conv":
+            continue
+        kh, kw, cin, _ = g.tensor(g.ops[i].inputs[1]).shape
+        rows.append({"op": i, "k_true": int(kh * kw * cin),
+                     "k_phys": int(lay.w_phys.shape[0]),
+                     "in_lanes": int(lay.in_lanes)})
+    return rows
+
+
 def measured_pads(plan: ExecutionPlan, batched: bool = False,
                   bucket: int = 1) -> int:
     """Pad primitives actually traced on this route (recursively, through

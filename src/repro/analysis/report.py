@@ -72,6 +72,8 @@ class AuditReport:
     # plan content address (repro.analysis.fingerprint) — lets the AOT
     # cache cross-check its manifest against this audit (finding C005)
     fingerprint: Optional[str] = None
+    # per planned conv: im2col K true / K' / in_lanes (budget.conv_contractions)
+    convs: List[Dict[str, int]] = dataclasses.field(default_factory=list)
 
     @property
     def findings(self) -> List[Finding]:
@@ -89,6 +91,7 @@ class AuditReport:
             "model": self.model,
             "use_pallas": self.use_pallas,
             "fingerprint": self.fingerprint,
+            "convs": list(self.convs),
             "ok": self.ok,
             "verifier": [f.as_dict() for f in self.verifier],
             "retrace": self.retrace,
@@ -137,6 +140,13 @@ def to_markdown(reports: List[AuditReport]) -> str:
                 "-" if budget is None else budget,
                 r.pads.get("traced", "-")))
         lines.append("")
+        if rep.convs:
+            lines.append("| conv op | K true | K' | in_lanes |")
+            lines.append("|---|---|---|---|")
+            for c in rep.convs:
+                lines.append(f"| {c['op']} | {c['k_true']} | {c['k_phys']}"
+                             f" | {c['in_lanes']} |")
+            lines.append("")
         if rep.retrace:
             lines.append(
                 "- no-retrace: buckets {} / staged pads {} — {}".format(

@@ -110,11 +110,13 @@ class OpLayout:
     inside every traced call. ``in_lanes``/``out_shape`` describe the padded
     activation layout the op consumes/produces; ``n_true`` is the logical
     channel count (the kernels zero everything beyond it, which is what
-    makes chained padded layers exact).
+    makes chained padded layers exact). A k×k conv whose input arrives
+    logical (graph input, unplanned producer) reads it at its real channel
+    width (``in_lanes == c_true``) and only its im2col K is rounded up.
     """
 
     kind: str            # "fc" | "conv" | "dwconv"
-    w_phys: np.ndarray   # fc: (K', N'); conv: (kh*kw*Cin', N'); dw: (kh, kw, C')
+    w_phys: np.ndarray   # fc, conv: (K', N') (conv K = kh*kw*in_lanes); dw: (kh, kw, C')
     consts: tuple        # 5 × (N',) per-channel folded constants
     lo: float            # fused-activation clamp bounds (static)
     hi: float
@@ -132,10 +134,11 @@ class LayoutPlan:
 
     ``phys`` describes the single-call trace (FC activations additionally
     keep their MXU row padding between ops). ``entry_phys`` maps graph-input
-    tensor ids to their lane-padded per-sample physical shape whenever a
-    planned Pallas op consumes them — the batched engine stages those inputs
-    pre-padded (one fused device pad covers bucket fill + entry lanes), so
-    the batched trace contains no entry pads at all."""
+    tensor ids to their lane-padded per-sample physical shape whenever the
+    planned Pallas ops consuming them read them wider than logical — the
+    batched engine stages those inputs pre-padded (one fused device pad
+    covers bucket fill + entry lanes), so the batched trace contains no
+    entry lane pads at all."""
 
     layouts: dict
     phys: dict
@@ -154,6 +157,7 @@ def plan_layout(g: G.Graph, folded: dict, paged=None) -> LayoutPlan:
     """
     paged = paged or {}
     layouts, phys = {}, {}
+    planned_out = set()  # tensors a planned op produces (padded layout)
     for i, op in enumerate(g.ops):
         fc = folded.get(i)
         if fc is None or paged.get(i):
@@ -178,14 +182,21 @@ def plan_layout(g: G.Graph, folded: dict, paged=None) -> LayoutPlan:
                            lo, hi, n, kp, (mp, np_), k, z_x)
         elif op.op == G.CONV_2D:
             kh, kw, cin, cout = w.shape
-            cin_p = round_up(cin, MXU_LANES)
             np_ = round_up(cout, MXU_LANES)
-            f = np.zeros((kh, kw, cin_p, cout), np.int8)
+            if kh * kw > 1 and op.inputs[0] not in planned_out:
+                # Input arrives logical: flatten the taps at the real
+                # channel width, then round K up (tap-major/channel-minor,
+                # as im2col_q builds the rows) — no lane pad of the input.
+                in_lanes = cin
+            else:
+                in_lanes = round_up(cin, MXU_LANES)
+            f = np.zeros((kh, kw, in_lanes, cout), np.int8)
             f[:, :, :cin, :] = w
-            w_phys = np.zeros((kh * kw * cin_p, np_), np.int8)
-            w_phys[:, :cout] = f.reshape(kh * kw * cin_p, cout)
+            k = kh * kw * in_lanes
+            w_phys = np.zeros((round_up(k, MXU_LANES), np_), np.int8)
+            w_phys[:k, :cout] = f.reshape(k, cout)
             lay = OpLayout("conv", w_phys, _planned_consts(fc, cout, np_),
-                           lo, hi, cout, cin_p, y_t.shape[:3] + (np_,),
+                           lo, hi, cout, in_lanes, y_t.shape[:3] + (np_,),
                            cin, z_x)
         else:  # DEPTHWISE_CONV_2D
             assert w.shape[3] == 1, (
@@ -198,20 +209,26 @@ def plan_layout(g: G.Graph, folded: dict, paged=None) -> LayoutPlan:
                            lo, hi, c, cp, y_t.shape[:3] + (cp,), c, z_x)
 
         layouts[i] = lay
+        planned_out.add(op.outputs[0])
         if tuple(lay.out_shape) != tuple(y_t.shape):
             phys[op.outputs[0]] = tuple(lay.out_shape)
 
-    # Graph inputs consumed by a planned op: record the lane-padded entry
+    # Graph inputs consumed by planned ops: record the lane-padded entry
     # layout so the batched path can stage inputs pre-padded (fusing the
-    # bucket zero-fill with the entry lane pad in ONE device pad).
-    entry_phys = {}
+    # bucket zero-fill with the entry lane pad in ONE device pad) — only
+    # where every planned consumer reads the input at the same lane width;
+    # otherwise it stays logical and each consumer pads its own view.
+    entry_lanes = {}
     input_ids = set(g.inputs)
     for i, lay in layouts.items():
         tid = g.ops[i].inputs[0]
         if tid in input_ids:
-            t = g.tensor(tid)
-            if t.shape[-1] != lay.in_lanes:
-                entry_phys[tid] = tuple(t.shape[:-1]) + (lay.in_lanes,)
+            entry_lanes.setdefault(tid, set()).add(lay.in_lanes)
+    entry_phys = {}
+    for tid, lanes in entry_lanes.items():
+        shape = tuple(g.tensor(tid).shape)
+        if len(lanes) == 1 and shape[-1] not in lanes:
+            entry_phys[tid] = shape[:-1] + tuple(lanes)
     return LayoutPlan(layouts, phys, entry_phys)
 
 

@@ -250,8 +250,9 @@ def _pad_border_planned(x_q, kh, kw, stride, padding, z_x: int, c_true: int):
 
 
 def qconv_planned(x_q, lay, *, kh, kw, stride, padding):
-    """Planned-layout Conv2D: lane-padded NHWC in (padded here only at graph
-    entry), lane-padded NHWC out with padding lanes zeroed."""
+    """Planned-layout Conv2D: NHWC in at the plan's ``in_lanes`` (a k×k
+    conv reading a logical input is planned at its real channel width, so
+    nothing pads here), lane-padded NHWC out with padding lanes zeroed."""
     stride = tuple(stride)
     x_q = _lane_pad(x_q, lay.in_lanes)
     x_q = _pad_border_planned(x_q, kh, kw, stride, padding, lay.z_x,

@@ -89,9 +89,10 @@ def test_person_planned_pallas_bit_exact(person_q):
 
 def test_person_plan_kills_interior_layout_churn(person_q):
     """Layer trace of the person model: no pad/slice between consecutive
-    Pallas-routed layers. Remaining pads are structural — ONE graph-entry
-    lane pad, one SAME halo pad per spatially-padded conv, and the im2col
-    row alignment of non-lane-multiple patch counts."""
+    Pallas-routed layers. Remaining pads are structural — ONE entry pad
+    (the final FC's rows, after the reshape; conv0 reads its one real
+    input lane), one SAME halo pad per spatially-padded conv, and the
+    im2col alignment of patch matrices whose rows or K miss 128."""
     qg, qx = person_q
     cm = CompiledModel(qg, use_pallas=True)
     spec = jax.ShapeDtypeStruct((1, 96, 96, 1), np.int8)
@@ -103,17 +104,29 @@ def test_person_plan_kills_interior_layout_churn(person_q):
                     if op.op in (G.CONV_2D, G.DEPTHWISE_CONV_2D)
                     and op.attrs["padding"] == "SAME"
                     and qg.tensor(op.inputs[1]).shape[0] > 1)
-    im2col_row_pads = sum(
-        1 for op in qg.ops if op.op == G.CONV_2D
-        and (np.prod(qg.tensor(op.outputs[0]).shape[:3]) % 128) != 0)
+    layouts = cm.plan.layouts
+    im2col_pads = sum(
+        1 for i, op in enumerate(qg.ops) if op.op == G.CONV_2D
+        and (np.prod(qg.tensor(op.outputs[0]).shape[:3]) % 128
+             or np.prod(qg.tensor(op.inputs[1]).shape[:2])
+             * layouts[i].in_lanes % 128))
     producer = {op.outputs[0]: i for i, op in enumerate(qg.ops)}
+
+    def arrives_planned(i):  # logical input already in the planned shape
+        shape, lay = tuple(qg.tensor(qg.ops[i].inputs[0]).shape), layouts[i]
+        if lay.kind == "fc":
+            return shape == (lay.out_shape[0], lay.in_lanes)
+        return shape[-1] == lay.in_lanes
+
     entry_pads = sum(  # pallas op fed by graph entry or a non-pallas op
-        1 for i in cm.plan.layouts
-        if producer.get(qg.ops[i].inputs[0]) not in cm.plan.layouts)
-    assert entry_pads == 2  # conv0 (graph input) + final FC (after reshape)
-    # entry lane pads + geometric halo pads + im2col row alignment —
+        1 for i in layouts
+        if producer.get(qg.ops[i].inputs[0]) not in layouts
+        and not arrives_planned(i))
+    assert layouts[0].in_lanes == 1  # conv0 reads the graph input as is
+    assert entry_pads == 1  # the final FC (after reshape)
+    # entry pads + geometric halo pads + im2col alignment —
     # NOTHING between consecutive pallas layers.
-    assert planned.get("pad", 0) == entry_pads + same_halo + im2col_row_pads, \
+    assert planned.get("pad", 0) == entry_pads + same_halo + im2col_pads, \
         planned
     # the per-call route additionally re-padded every layer's operands
     assert percall.get("pad", 0) > 4 * planned.get("pad", 0)
@@ -141,10 +154,9 @@ def test_mixed_boundaries_pallas_paged_batched():
 
 def test_pad_budget_reproduces_person_pin(person_q):
     """The plan auditor derives the same structural pad count the test
-    above pins by hand (entry lane pads + SAME halos + im2col row
-    alignment) — the hand-derived formula now has a single authoritative
-    derivation in ``repro.analysis.budget`` that the traced jaxpr must
-    match exactly."""
+    above pins by hand (entry pads + SAME halos + im2col alignment) — the
+    hand-derived formula now has a single authoritative derivation in
+    ``repro.analysis.budget`` that the traced jaxpr must match exactly."""
     from repro.analysis import measured_pads, pad_budget
     qg, _ = person_q
     cm = CompiledModel(qg, use_pallas=True)

@@ -64,10 +64,15 @@ def _consts(spec, n):
 
 
 # (M, K, N) and (padded H×W, lanes, stride) as the planned route hands them
-# to the kernels; conv shapes are after im2col / SAME pre-padding.
+# to the kernels; conv shapes are after im2col / SAME pre-padding. The thin
+# entry convs read their one real input lane (K = 80 and 9, rounded up to
+# 128); ``speech_conv_im2col`` is the same 10x8 conv on 128 input lanes, the
+# layout a conv fed by a planned op keeps.
 CASES = {
     "sine_fc": _qmatmul(128, 128, 128),
     "speech_conv_im2col": _qmatmul(512, 10240, 128),
+    "speech_conv_im2col_thin": _qmatmul(512, 128, 128),
+    "person_conv0_im2col_thin": _qmatmul(2304, 128, 128),
     "person_pw12": _qmatmul(128, 256, 256),
     "person_dw0_stride1": _qdwconv((50, 50), 128, 1),
     "person_dw1_stride2": _qdwconv((49, 49), 128, 2),
