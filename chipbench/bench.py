@@ -21,6 +21,8 @@ import time
 
 import numpy as np
 
+from chipbench import model
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 POOL_ROWS = 1024  # distinct input rows per run; requests cycle through them
@@ -61,6 +63,7 @@ class Cell:
         self.name = name
         self.chips = self.workload["chips"]
         self.cfg = load_json("configs", self.workload["config"] + ".json")
+        model.layer_shapes(self.cfg)  # refuses a malformed layer graph
         self.traffic = load_json("traffic", self.workload["traffic"] + ".json")
         self.loop = load_file_module("loops", self.traffic["loop"] + ".py")
         self.end_to_end = [m for m in bench["end_to_end"]
@@ -91,10 +94,8 @@ def build_registry(cell: Cell, params, *, tracer=None, prepare=None):
     from repro.core import CompiledModel
     from repro.serve.registry import ServingRegistry
 
-    from chipbench.model import build_int8
-
     r = cell.cfg["registry"]
-    qg = build_int8(cell.cfg, params)
+    qg = model.build_int8(cell.cfg, params)
     cm = CompiledModel(qg, use_pallas=True)
     reg = ServingRegistry(max_batch=r["max_batch"],
                           max_delay_s=r["max_delay_s"],

@@ -5,24 +5,87 @@ The float weights are drawn by the benchmark from the configuration's own
 weight seed, so the plain reference (``chipbench.reference``) and the
 program start from the same numbers without the reference taking anything
 the program made.
+
+The layers form a graph: a layer reads the outputs its ``inputs`` key
+names, or the previous layer's output without it (see :func:`graph`), so a
+branch and its skip connection are written as layers that name their
+inputs. Every walk over the layers (shapes, weights, the program's graph,
+the reference) goes through :func:`walk`.
 """
 import importlib
 
 import numpy as np
 
 
+INPUT = "input"  # the name by which a layer reads the graph input
+
+
 def op_module(kind: str):
     return importlib.import_module(f"chipbench.ops.{kind}")
 
 
-def layer_shapes(cfg) -> list:
-    """[(layer, in_shape, out_shape)] per row, in order."""
-    out, shp = [], tuple(cfg["input_shape"])
-    for layer in cfg["layers"]:
-        nxt = tuple(op_module(layer["op"]).shape(layer, shp))
-        out.append((layer, shp, nxt))
-        shp = nxt
+def arity(mod) -> int:
+    """Inputs a layer kind takes: ``INPUTS`` of its module, 1 by default."""
+    return getattr(mod, "INPUTS", 1)
+
+
+def graph(cfg) -> list:
+    """[(layer, names of the outputs it reads)] in order.
+
+    A layer reads the outputs its ``inputs`` key names: earlier layers'
+    ``name``s, or ``INPUT`` for the graph input. Without the key it reads
+    the previous layer's output (the first layer, the graph input). The
+    last layer's output is the graph's. A name that is no earlier layer's,
+    a name used twice and a count of inputs its kind does not take are
+    refused, naming the layer."""
+    seen, prev, out = {INPUT}, INPUT, []
+    for i, layer in enumerate(cfg["layers"]):
+        name = layer.get("name")
+        if not isinstance(name, str) or name in seen:
+            raise ValueError(f"layer {i} ({name!r}): every layer needs a "
+                             f"name of its own, other than {INPUT!r}")
+        names = layer.get("inputs", [prev])
+        if not isinstance(names, list) or not names:
+            raise ValueError(f"layer {name!r}: 'inputs' must be a list of "
+                             f"names")
+        for n in names:
+            if n not in seen:
+                raise ValueError(f"layer {name!r} reads {n!r}, which is not "
+                                 f"an earlier layer or {INPUT!r}")
+        want = arity(op_module(layer["op"]))
+        if len(names) != want:
+            raise ValueError(f"layer {name!r} reads {len(names)} inputs; "
+                             f"{layer['op']!r} takes {want}")
+        seen.add(name)
+        prev = name
+        out.append((layer, names))
     return out
+
+
+def walk(cfg, x, step) -> list:
+    """[(layer, its input, its output)] in order, where ``step(layer, mod,
+    input)`` gives a layer's output and ``x`` is the graph input's. A
+    layer's input is the one output it reads, or the tuple of them for a
+    kind of several inputs; the values may be shapes, tensors or arrays."""
+    values, out = {INPUT: x}, []
+    for layer, names in graph(cfg):
+        mod = op_module(layer["op"])
+        args = tuple(values[n] for n in names)
+        xin = args[0] if arity(mod) == 1 else args
+        y = values[layer["name"]] = step(layer, mod, xin)
+        out.append((layer, xin, y))
+    return out
+
+
+def layer_shapes(cfg) -> list:
+    """[(layer, in_shape, out_shape)] per row, in order; ``in_shape`` is a
+    tuple of shapes for a kind of several inputs."""
+    def shape(layer, mod, x_shape):
+        try:
+            return tuple(mod.shape(layer, x_shape))
+        except ValueError as e:
+            raise ValueError(f"layer {layer['name']!r}: {e}") from None
+    return walk(cfg, tuple(cfg["input_shape"]), shape)
 
 
 def ops_per_row(cfg) -> int:
@@ -61,10 +124,12 @@ def build_int8(cfg, params):
     from repro.core.quantize import quantize_graph
 
     gb = GraphBuilder(cfg["name"])
-    x = gb.input("x", (1,) + tuple(cfg["input_shape"]))
-    for (layer, _, _), p in zip(layer_shapes(cfg), params):
-        x = op_module(layer["op"]).build(gb, x, layer, p)
-    gb.output(x)
+    weights = {layer["name"]: p
+               for (layer, _), p in zip(graph(cfg), params)}
+    built = walk(cfg, gb.input("x", (1,) + tuple(cfg["input_shape"])),
+                 lambda layer, mod, x: mod.build(gb, x, layer,
+                                                 weights[layer["name"]]))
+    gb.output(built[-1][2])
     cal = cfg["calibration"]
     rng = np.random.default_rng(cal["seed"])
     rep = [draw_inputs(cfg, rng, 1) for _ in range(cal["samples"])]

@@ -14,6 +14,17 @@ Each module gives, for its layer kind:
 * ``WEIGHT_AXIS``: for layers with weights, the output-channel axis of
   ``params["w"]``.
 
+A layer reads the previous layer's output, or the outputs its ``inputs``
+key names: earlier layers' ``name``s, or ``"input"`` for the graph input
+(``chipbench.model.graph``). So a skip connection is a layer whose
+``inputs`` name the block's output and the block's input, and a
+projection shortcut a 1x1 ``conv`` whose ``inputs`` name the block's
+input. A kind that takes several inputs sets ``INPUTS`` to their number;
+its functions then get the tuple of input shapes (``x_shapes``), tensor
+ids (``xs`` in ``build``) or values (``xs`` in ``ref``), in the order of
+``inputs``, where a kind of one input gets the one (``add.py``). A layer
+naming another number of inputs than its kind takes is refused.
+
 A configuration that needs a new layer kind adds a module here.
 """
 import jax.numpy as jnp

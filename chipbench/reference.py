@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.model import layer_shapes, op_module
+from chipbench.model import graph, op_module, walk
 
 BLOCK_ROWS = 256  # rows per reference call, so the host never holds more
 
@@ -45,7 +45,7 @@ class Reference:
     def __init__(self, cfg, params):
         self.cfg, self.params = cfg, params
         self.layers = [(layer, op_module(layer["op"]))
-                       for layer, _, _ in layer_shapes(cfg)]
+                       for layer, _ in graph(cfg)]
         self._fns: dict = {}
 
     def _fn(self, bits, ranges):
@@ -56,15 +56,21 @@ class Reference:
         return self._fns[key]
 
     def _forward(self, params, x, *, bits, ranges):
+        # with ``bits``, each output is quantized as it is made, and its
+        # later readers get the quantized value, as in an int8 program
         acts = [x]
         if bits:
             x = _fq_act(x, *ranges[0], bits)
-        for i, ((layer, mod), p) in enumerate(zip(self.layers, params)):
-            x = mod.ref(x, layer, p)
+        weights = {layer["name"]: p
+                   for (layer, _), p in zip(self.layers, params)}
+
+        def step(layer, mod, xin):
+            y = mod.ref(xin, layer, weights[layer["name"]])
             if bits:
-                x = _fq_act(x, *ranges[i + 1], bits)
-            acts.append(x)
-        return x, acts
+                y = _fq_act(y, *ranges[len(acts)], bits)
+            acts.append(y)
+            return y
+        return walk(self.cfg, x, step)[-1][2], acts
 
     def _run(self, fn, params, x):
         cpu = jax.devices("cpu")[0]

@@ -19,9 +19,12 @@ _HERE = bench.HERE
 def cpu_cell(tmp_path, config="speech_tinyconv", traffic=None, files=(),
              max_batch=8, per_layer=()):
     """Yields a function running the throwaway cell ``zz.cpu`` through
-    ``run.main`` (look for a chip skipped); removes its files after."""
-    cfg = bench.load_json("configs", config + ".json")
-    cfg.update(name="zz_cpu_" + config)
+    ``run.main`` (look for a chip skipped); removes its files after.
+    ``config`` names a configuration file, or is a configuration itself."""
+    cfg = (dict(config) if isinstance(config, dict)
+           else bench.load_json("configs", config + ".json"))
+    cfg.update(name="zz_cpu_" + cfg["name"])
+    cfg["registry"] = dict(cfg["registry"])
     cfg["registry"].update(max_batch=max_batch)
     made = {os.path.join(_HERE, "configs", cfg["name"] + ".json"):
             json.dumps(cfg),
